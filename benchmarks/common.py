@@ -72,13 +72,10 @@ class Timer:
 
 
 def time_call(fn, *args, repeat: int = 3, **kw):
-    fn(*args, **kw)  # warm up / compile
+    import jax
+    jax.block_until_ready(fn(*args, **kw))  # warm up / compile
     t0 = time.perf_counter()
     for _ in range(repeat):
         r = fn(*args, **kw)
-    try:
-        import jax
-        jax.block_until_ready(r)
-    except Exception:
-        pass
+    jax.block_until_ready(r)
     return (time.perf_counter() - t0) / repeat * 1e6  # us

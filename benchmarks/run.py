@@ -582,6 +582,8 @@ def main(argv=None) -> None:
     if unknown:
         raise SystemExit(f"unknown bench(es) {unknown}; "
                          f"available: {list(ALL_BENCHES)}")
+    from repro.launch import compile_cache
+    compile_cache.enable()
     for name in names:
         fn = globals()[name]
         kw = {"rounds": rounds} if rounds is not None and \

@@ -250,6 +250,12 @@ class FleetKernel:
     def __call__(self, *args):
         return self._jit(*args)
 
+    def lower(self, *args):
+        """The replicated jit's ``jax.stages.Lowered`` for ``args`` (arrays
+        or ``ShapeDtypeStruct``s): ``.compile()`` it to read the program's
+        HLO and memory analysis without running it."""
+        return self._jit.lower(*args)
+
     def sharded(self, mesh):
         """The shard-mapped variant for ``mesh`` (cached per mesh)."""
         key = (tuple(d.id for d in mesh.devices.flat), mesh.axis_names)
@@ -259,7 +265,6 @@ class FleetKernel:
         return fn
 
     def _build_sharded(self, mesh):
-        from jax.experimental.shard_map import shard_map
         from repro.launch.sharding import fleet_axes
         axes = fleet_axes(mesh)
         ns, impl, specs = self.n_static, self.impl, self.specs
@@ -269,9 +274,9 @@ class FleetKernel:
             statics, arrays = args[:ns], args[ns:]
             in_specs, out_specs = specs(axes, *arrays)
             body = functools.partial(impl, *statics, axis_name=axes)
-            return shard_map(lambda *a: body(*a), mesh=mesh,
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_rep=False)(*arrays)
+            return jax.shard_map(lambda *a: body(*a), mesh=mesh,
+                                 in_specs=in_specs, out_specs=out_specs,
+                                 check_vma=False)(*arrays)
 
         def run(*args):
             # canonicalize placement BEFORE the jit boundary: the jit

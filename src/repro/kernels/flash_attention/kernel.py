@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -66,7 +68,7 @@ def _attn_kernel(meta_ref, q_ref, k_ref, v_ref, out_ref,
                                              "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          bq: int = 128, bk: int = 128,
-                         interpret: bool = True):
+                         interpret: bool = None):
     """q [B,H,Sq,hd]; k,v [B,K,Skv,hd] (H % K == 0). Returns [B,H,Sq,hd]."""
     B, H, Sq, hd = q.shape
     K = k.shape[1]
@@ -94,5 +96,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(meta, q, k, v)

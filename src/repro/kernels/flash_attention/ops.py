@@ -5,13 +5,10 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import kernel as K
 
-_INTERPRET = True
-
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     interpret=None):
     """q [B,Sq,H,hd]; k,v [B,Skv,Kh,hd] -> [B,Sq,H,hd]."""
-    interpret = _INTERPRET if interpret is None else interpret
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

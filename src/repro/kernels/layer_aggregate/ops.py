@@ -5,12 +5,9 @@ import jax.numpy as jnp
 
 from repro.kernels.layer_aggregate import kernel as K
 
-_INTERPRET = True
-
 
 def aggregate_leaf(c, ww, s, lam, *, interpret=None):
     """c [N, L, ...]; ww [N, L]; s [L, ...] -> [L, ...]."""
-    interpret = _INTERPRET if interpret is None else interpret
     N, Lk = c.shape[:2]
     F = 1
     for dim in c.shape[2:]:

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
                 h_ref, *, cl, nc):
@@ -66,7 +68,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, A, B, C, D=None, *, chunk: int = 128,
-             interpret: bool = True):
+             interpret: bool = None):
     """x [Bt,S,nh,hd]; dt [Bt,S,nh]; A [nh]; B,C [Bt,S,st]; D [nh] or None.
 
     Returns (y [Bt,S,nh,hd], h_final [Bt,nh,hd,st]).
@@ -100,6 +102,6 @@ def ssd_scan(x, dt, A, B, C, D=None, *, chunk: int = 128,
             jax.ShapeDtypeStruct((Bt, nh, hd, st), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, st), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xt, dtt, A.astype(jnp.float32), B, C, D.astype(jnp.float32))
     return jnp.transpose(y, (0, 2, 1, 3)), h

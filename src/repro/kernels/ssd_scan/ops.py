@@ -3,9 +3,6 @@ from __future__ import annotations
 
 from repro.kernels.ssd_scan import kernel as K
 
-_INTERPRET = True
-
 
 def ssd_scan(x, dt, A, B, C, D=None, *, chunk: int = 128, interpret=None):
-    interpret = _INTERPRET if interpret is None else interpret
     return K.ssd_scan(x, dt, A, B, C, D, chunk=chunk, interpret=interpret)

@@ -6,8 +6,6 @@ import jax.numpy as jnp
 
 from repro.kernels.tpgf_fusion import kernel as K
 
-_INTERPRET = True  # CPU container: interpret-mode; flips to False on TPU
-
 
 def _to_tiles(x):
     """Flatten to [M, LANE] padded to ROW_BLOCK rows; remember true size."""
@@ -20,7 +18,6 @@ def _to_tiles(x):
 
 
 def fuse_leaf(a, b, w_client, clip_scale, *, interpret=None):
-    interpret = _INTERPRET if interpret is None else interpret
     ta, n = _to_tiles(a)
     tb, _ = _to_tiles(b)
     out = K.fuse_2d(ta, tb, w_client, clip_scale, interpret=interpret)
@@ -34,7 +31,6 @@ def tier_sum_leaf(leaves, weights, *, interpret=None):
     tier in canonical order; ``weights`` the matching normalized fp32
     scalars. Tiles each leaf, stacks the tier axis, and runs the one-pass
     ``tier_sum_2d`` accumulator. Returns fp32 (``fuse_tiers`` casts)."""
-    interpret = _INTERPRET if interpret is None else interpret
     tiles, n = zip(*(_to_tiles(x) for x in leaves))
     out = K.tier_sum_2d(jnp.stack(tiles), jnp.stack(weights),
                         interpret=interpret)
@@ -45,7 +41,6 @@ def fuse_tree(g_client, g_server, w_client, *, tau: float = None,
               interpret=None):
     """Eq. 4 over a pytree. If ``tau`` is given, also computes the global-l2
     clip scale with the sumsq kernel (Phase-1 clip fused into the blend)."""
-    interpret = _INTERPRET if interpret is None else interpret
     if tau is not None:
         total = jnp.float32(0.0)
         for leaf in jax.tree.leaves(g_client):

@@ -55,18 +55,10 @@ def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
 
 
 def make_abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Device-free mesh for sharding-rule validation.
-
-    jax >= 0.4.36 changed ``AbstractMesh`` to take ``((name, size), ...)``
-    instead of ``(sizes, names)``; this helper accepts the old-style pair
-    and builds whichever form the installed jax expects.
-    """
+    """Device-free mesh for sharding-rule validation."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:        # older jax: positional (shape, axis_names)
-        return AbstractMesh(shape, axes)
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def fsdp_axes(mesh) -> tuple:

@@ -153,7 +153,11 @@ def invariants():
         assert c.state.round_idx == 1
         # restore must re-apply the client-axis placement (fleet_pspecs)
         head = jax.tree.leaves(c.state.local_heads)[0]
-        assert head.sharding.spec[0] == ("data",), head.sharding
+        from jax.sharding import PartitionSpec as P
+        from repro.launch.sharding import fleet_axes
+        # PartitionSpec normalizes a one-axis tuple to the bare name
+        assert head.sharding.spec[0] == P(fleet_axes(mesh))[0], \
+            head.sharding
         c.run_round()
     for x, y in zip(jax.tree.leaves((a.state.params, a.state.local_heads,
                                      a.state.opt_state)),

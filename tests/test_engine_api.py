@@ -17,19 +17,20 @@ from repro.federated.strategies.base import Strategy
 METHODS = ("ssfl", "sfl", "dfl", "fedavg")
 
 # Golden 2-round records produced by the pre-refactor seed trainer
-# (commit 11d6a28) on this exact setting: vit16_cifar reduced to
+# (commit 11d6a28, re-run under jax/jaxlib 0.9.0 on the CPU backend) on
+# this exact setting: vit16_cifar reduced to
 # n_layers=4/d_model=48/n_heads=4/head_dim=12/d_ff=96/image_size=16/
 # n_classes=6, n_clients=5, seed=0, lr=0.3, local_steps=2, batch_size=8,
 # availability=0.7. The engine must reproduce them within 1e-5.
 SEED_GOLDEN = {
-    "ssfl": [{"loss": 1.733882517260262, "comm_mb": 2.56, "time_s": 1.16},
-             {"loss": 1.6497505946508355, "comm_mb": 5.02, "time_s": 2.33}],
-    "sfl": [{"loss": 1.7448828220367432, "comm_mb": 2.08, "time_s": 1.17},
-            {"loss": 1.7244073152542114, "comm_mb": 3.47, "time_s": 2.34}],
-    "dfl": [{"loss": 1.744882845878601, "comm_mb": 2.08, "time_s": 1.17},
-            {"loss": 1.7244112968444825, "comm_mb": 3.47, "time_s": 2.34}],
-    "fedavg": [{"loss": 1.6937156915664673, "comm_mb": 1.8, "time_s": 0.41},
-               {"loss": 1.6152817010879517, "comm_mb": 3.01, "time_s": 0.83}],
+    "ssfl": [{"loss": 1.7321600294675696, "comm_mb": 2.56, "time_s": 1.16},
+             {"loss": 1.64927921416254, "comm_mb": 5.02, "time_s": 2.33}],
+    "sfl": [{"loss": 1.7432544946670532, "comm_mb": 2.08, "time_s": 1.17},
+            {"loss": 1.7295214176177978, "comm_mb": 3.47, "time_s": 2.34}],
+    "dfl": [{"loss": 1.7432544946670532, "comm_mb": 2.08, "time_s": 1.17},
+            {"loss": 1.7295230627059937, "comm_mb": 3.47, "time_s": 2.34}],
+    "fedavg": [{"loss": 1.6961787939071655, "comm_mb": 1.8, "time_s": 0.41},
+               {"loss": 1.6326570510864258, "comm_mb": 3.01, "time_s": 0.83}],
 }
 
 

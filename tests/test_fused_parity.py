@@ -15,16 +15,17 @@ import pytest
 from repro.configs import base
 from repro.federated import Engine
 
-# Pre-refactor engine records: vit16_cifar reduced to n_layers=4/d_model=48/
+# Pre-refactor engine records (commit 735bb12, re-run under jax/jaxlib
+# 0.9.0 on the CPU backend): vit16_cifar reduced to n_layers=4/d_model=48/
 # n_heads=4/head_dim=12/d_ff=96/image_size=16/n_classes=6, n_clients=6,
 # seed=0, lr=0.3, local_steps=2, batch_size=8, availability=0.8.
 PRE_REFACTOR_GOLDEN = {
-    "ssfl": [{"loss": 1.7477002516768563, "comm_mb": 2.54, "time_s": 1.16},
-             {"loss": 1.7418298603626192, "comm_mb": 5.17, "time_s": 2.31}],
-    "sfl": [{"loss": 1.7646270036697387, "comm_mb": 2.08, "time_s": 1.04},
-            {"loss": 1.7266807079315185, "comm_mb": 4.86, "time_s": 2.08}],
-    "fedavg": [{"loss": 1.739494800567627, "comm_mb": 2.4, "time_s": 0.45},
-               {"loss": 1.7335288524627686, "comm_mb": 5.41, "time_s": 0.9}],
+    "ssfl": [{"loss": 1.7477719177146558, "comm_mb": 2.54, "time_s": 1.16},
+             {"loss": 1.7392217234149328, "comm_mb": 5.17, "time_s": 2.31}],
+    "sfl": [{"loss": 1.7640077114105224, "comm_mb": 2.08, "time_s": 1.04},
+            {"loss": 1.7273731708526612, "comm_mb": 4.86, "time_s": 2.08}],
+    "fedavg": [{"loss": 1.7428882122039795, "comm_mb": 2.4, "time_s": 0.45},
+               {"loss": 1.7359342575073242, "comm_mb": 5.41, "time_s": 0.9}],
 }
 
 

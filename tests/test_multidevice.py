@@ -98,9 +98,11 @@ class TestFallbacks:
 
     def test_single_device_fleet_mesh_runs_replicated(self):
         import jax
+        from jax.sharding import PartitionSpec as P
         from repro.federated.bucketing import FleetKernel
         from repro.federated.strategies.ssfl import cohort_kernel
         from repro.launch.mesh import make_fleet_mesh
+        from repro.launch.sharding import fleet_axes
         eng = self._engine(mesh=make_fleet_mesh(1))
         assert eng.fleet_shards == 1
         assert isinstance(cohort_kernel, FleetKernel)
@@ -108,7 +110,8 @@ class TestFallbacks:
         assert eng.kernel_fn(cohort_kernel, 8) is cohort_kernel
         assert np.isfinite(eng.run_round()["loss"])
         head = jax.tree.leaves(eng.state.local_heads)[0]
-        assert head.sharding.spec[0] == ("data",)
+        # PartitionSpec normalizes a one-axis tuple to the bare name
+        assert head.sharding.spec[0] == P(fleet_axes(eng.mesh))[0]
 
     def test_non_dividing_bucket_falls_back(self):
         """An explicit ladder whose entry resists the shard rounding can
