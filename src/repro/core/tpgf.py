@@ -8,6 +8,10 @@ Phase 3 (client): loss-weighted fusion (Eq. 3/4) of the two encoder grads.
 
 Everything returns *gradients*; the optimizer application lives in
 ``repro.optim`` so the same step works under SGD/AdamW and under pjit.
+The client-side work (prefix forward, both prefix backward passes, local
+head, clip, fusion) runs under the device name scope ``tpgf.client`` and
+the server suffix's value-and-grad under ``tpgf.server`` (metadata only;
+the profiler attributes device time by them).
 """
 from __future__ import annotations
 
@@ -135,36 +139,41 @@ def tpgf_grads(cfg: ModelConfig, params, batch, d, *,
         full = SN.merge_params(cfg, cp, server_p, local_p)
         return M.prefix_apply(cfg, full, batch, d)
 
-    (z, aux_prefix), vjp_prefix = jax.vjp(prefix_fn, client_p)
-
-    # ---- Phase 1: local supervision
     def local_fn(lp, z_):
         full = SN.merge_params(cfg, client_p, server_p, lp)
         return M.local_loss(cfg, full, z_, batch)
 
-    loss_client, (g_local, gz_client) = jax.value_and_grad(
-        local_fn, argnums=(0, 1))(local_p, z)
-
-    # ---- Phase 2: server supervision
     def server_fn(sp, z_):
         full = SN.merge_params(cfg, client_p, sp, local_p)
         return M.server_loss(cfg, full, z_, batch, d)
 
-    loss_server, (g_server_params, gz_server) = jax.value_and_grad(
-        server_fn, argnums=(0, 1))(server_p, z)
+    with jax.named_scope("tpgf.client"):
+        # ---- shared prefix forward with a single vjp (Alg. 2, line 13)
+        (z, aux_prefix), vjp_prefix = jax.vjp(prefix_fn, client_p)
+        # ---- Phase 1: local supervision
+        loss_client, (g_local, gz_client) = jax.value_and_grad(
+            local_fn, argnums=(0, 1))(local_p, z)
 
-    # client backprop of each branch's dL/dz through the encoder
-    (g_client_local,) = vjp_prefix((gz_client, jnp.zeros_like(aux_prefix)))
-    (g_client_server,) = vjp_prefix((gz_server, jnp.zeros_like(aux_prefix)))
+    # ---- Phase 2: server supervision
+    with jax.named_scope("tpgf.server"):
+        loss_server, (g_server_params, gz_server) = jax.value_and_grad(
+            server_fn, argnums=(0, 1))(server_p, z)
 
-    # ---- Phase 3: clip + loss-weighted fusion (Eqs. 3-4)
-    g_client_local, _ = clip_by_global_l2(g_client_local, cfg.tpgf_clip)
-    w_c = tpgf_weight(loss_client, loss_server, d, d_s, cfg.tpgf_eps,
-                      variant=cfg.tpgf_variant)
-    g_client = fuse_gradients(g_client_local, g_client_server, w_c,
-                              use_pallas=cfg.use_pallas)
-    w_c, g_server_params, g_client = _fault_degrade(
-        server_available, w_c, g_server_params, g_client, g_client_local)
+    with jax.named_scope("tpgf.client"):
+        # client backprop of each branch's dL/dz through the encoder
+        (g_client_local,) = vjp_prefix((gz_client,
+                                        jnp.zeros_like(aux_prefix)))
+        (g_client_server,) = vjp_prefix((gz_server,
+                                         jnp.zeros_like(aux_prefix)))
+        # ---- Phase 3: clip + loss-weighted fusion (Eqs. 3-4)
+        g_client_local, _ = clip_by_global_l2(g_client_local, cfg.tpgf_clip)
+        w_c = tpgf_weight(loss_client, loss_server, d, d_s, cfg.tpgf_eps,
+                          variant=cfg.tpgf_variant)
+        g_client = fuse_gradients(g_client_local, g_client_server, w_c,
+                                  use_pallas=cfg.use_pallas)
+        w_c, g_server_params, g_client = _fault_degrade(
+            server_available, w_c, g_server_params, g_client,
+            g_client_local)
 
     grads = SN.merge_params(cfg, g_client, g_server_params, g_local)
     return TPGFOut(grads, loss_client, loss_server, w_c, aux_prefix)
@@ -202,39 +211,44 @@ def tpgf_grads_split(cfg: ModelConfig, wcfg: ModelConfig, client_p, server_p,
     d_s = cfg.split_stack_len - d
     length = None if M.static_depth(d) else d
 
-    # ---- shared prefix forward with a single vjp (Algorithm 2, line 13)
     def prefix_fn(cp):
         return M.client_apply(wcfg, cp, batch, length=length)
 
-    (z, aux_prefix), vjp_prefix = jax.vjp(prefix_fn, client_p)
-
-    # ---- Phase 1: local supervision (the local head is width-oblivious —
-    # it reads the full-d_model smashed data)
+    # the local head is width-oblivious — it reads the full-d_model
+    # smashed data
     def local_fn(lp, z_):
         return M.local_loss(cfg, lp, z_, batch)
 
-    loss_client, (g_local, gz_client) = jax.value_and_grad(
-        local_fn, argnums=(0, 1))(local_p, z)
-
-    # ---- Phase 2: server supervision (full-width suffix)
     def server_fn(sp, z_):
         return M.server_split_loss(cfg, sp, z_, batch, length=length)
 
-    loss_server, (g_server_params, gz_server) = jax.value_and_grad(
-        server_fn, argnums=(0, 1))(server_p, z)
+    with jax.named_scope("tpgf.client"):
+        # ---- shared prefix forward with a single vjp (Alg. 2, line 13)
+        (z, aux_prefix), vjp_prefix = jax.vjp(prefix_fn, client_p)
+        # ---- Phase 1: local supervision
+        loss_client, (g_local, gz_client) = jax.value_and_grad(
+            local_fn, argnums=(0, 1))(local_p, z)
 
-    # client backprop of each branch's dL/dz through the encoder slice
-    (g_client_local,) = vjp_prefix((gz_client, jnp.zeros_like(aux_prefix)))
-    (g_client_server,) = vjp_prefix((gz_server, jnp.zeros_like(aux_prefix)))
+    # ---- Phase 2: server supervision (full-width suffix)
+    with jax.named_scope("tpgf.server"):
+        loss_server, (g_server_params, gz_server) = jax.value_and_grad(
+            server_fn, argnums=(0, 1))(server_p, z)
 
-    # ---- Phase 3: clip + loss-weighted fusion (Eqs. 3-4)
-    g_client_local, _ = clip_by_global_l2(g_client_local, cfg.tpgf_clip)
-    w_c = tpgf_weight(loss_client, loss_server, d, d_s, cfg.tpgf_eps,
-                      variant=cfg.tpgf_variant)
-    g_client = fuse_gradients(g_client_local, g_client_server, w_c,
-                              use_pallas=cfg.use_pallas)
-    w_c, g_server_params, g_client = _fault_degrade(
-        server_available, w_c, g_server_params, g_client, g_client_local)
+    with jax.named_scope("tpgf.client"):
+        # client backprop of each branch's dL/dz through the encoder slice
+        (g_client_local,) = vjp_prefix((gz_client,
+                                        jnp.zeros_like(aux_prefix)))
+        (g_client_server,) = vjp_prefix((gz_server,
+                                         jnp.zeros_like(aux_prefix)))
+        # ---- Phase 3: clip + loss-weighted fusion (Eqs. 3-4)
+        g_client_local, _ = clip_by_global_l2(g_client_local, cfg.tpgf_clip)
+        w_c = tpgf_weight(loss_client, loss_server, d, d_s, cfg.tpgf_eps,
+                          variant=cfg.tpgf_variant)
+        g_client = fuse_gradients(g_client_local, g_client_server, w_c,
+                                  use_pallas=cfg.use_pallas)
+        w_c, g_server_params, g_client = _fault_degrade(
+            server_available, w_c, g_server_params, g_client,
+            g_client_local)
     return TPGFSplitOut(g_client, g_server_params, g_local,
                         loss_client, loss_server, w_c, aux_prefix)
 

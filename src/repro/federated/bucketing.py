@@ -241,7 +241,8 @@ class FleetKernel:
         self.impl = impl
         self.n_static = n_static
         self.specs = specs
-        self._jit = jax.jit(functools.partial(impl, axis_name=None),
+        self._jit = jax.jit(_named(functools.partial(impl, axis_name=None),
+                                   impl),
                             static_argnums=tuple(range(n_static)))
         self._sharded = {}
         self._sanitized = None
@@ -269,14 +270,16 @@ class FleetKernel:
         axes = fleet_axes(mesh)
         ns, impl, specs = self.n_static, self.impl, self.specs
 
-        @functools.partial(jax.jit, static_argnums=tuple(range(ns)))
-        def jitted(*args):
+        def mapped(*args):
             statics, arrays = args[:ns], args[ns:]
             in_specs, out_specs = specs(axes, *arrays)
             body = functools.partial(impl, *statics, axis_name=axes)
             return jax.shard_map(lambda *a: body(*a), mesh=mesh,
                                  in_specs=in_specs, out_specs=out_specs,
                                  check_vma=False)(*arrays)
+
+        jitted = jax.jit(_named(mapped, impl),
+                         static_argnums=tuple(range(ns)))
 
         def run(*args):
             # canonicalize placement BEFORE the jit boundary: the jit
@@ -324,13 +327,21 @@ class FleetKernel:
                 traced,
                 errors=checkify.float_checks | checkify.user_checks)
             self._sanitized = jax.jit(
-                fn, static_argnums=tuple(range(self.n_static)))
+                _named(fn, impl), static_argnums=tuple(range(self.n_static)))
         return self._sanitized
 
     def _cache_size(self) -> int:
         return (self._jit._cache_size()
                 + (self._sanitized._cache_size() if self._sanitized else 0)
                 + sum(f._cache_size() for f in self._sharded.values()))
+
+
+def _named(fn, impl):
+    """``fn`` under ``impl``'s name, so its jit compiles as
+    ``jit_<impl name>`` (a bare ``functools.partial`` compiles as
+    ``jit__unknown``) and the profiler's trace shows which kernel ran."""
+    fn.__name__ = fn.__qualname__ = impl.__name__
+    return fn
 
 
 def _place(arrays, in_specs, mesh):

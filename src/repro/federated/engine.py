@@ -80,12 +80,17 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.fault import ArrivalProcess, AvailabilityModel
+from repro.federated import bucketing as BK
 from repro.federated import metrics as MET
+from repro.federated import tracing
 from repro.federated.simulator import make_fleet
 from repro.federated.state import TrainState, init_train_state
 from repro.federated.strategies import RoundContext, Strategy, get_strategy
 from repro.models import model as M
 from repro.optim import Optimizer, get_optimizer
+
+# per-round kernel counters, counted by Engine.launch_kernel
+_ROUND_COUNTS = ("kernel_launches", "kernel_slots", "kernel_real_slots")
 
 
 class Engine:
@@ -186,6 +191,7 @@ class Engine:
         self._staleness = np.zeros(n_clients, np.int64)
         self._server_updates = 0    # rounds in which any client had a server
         self.history: List[Dict] = []
+        self._counts = dict.fromkeys(_ROUND_COUNTS, 0)
 
     def _call_prepare_fleet(self, cfg, fleet):
         """Pass ``device_model`` only to hooks that accept it, so strategies
@@ -268,38 +274,82 @@ class Engine:
 
     # ------------------------------------------------------------- one round
     def run_round(self) -> Dict:
+        """One federated round. Returns (and appends to ``history``) the
+        round record: ``round``, ``loss``, the kernel counters of the
+        round (``kernel_launches``, ``kernel_slots``,
+        ``kernel_real_slots``, ``kernel_compiles``; host integers, no
+        device sync) and the accountant's running summary. Every phase
+        runs under a host span (``tracing.span``; docs/architecture.md,
+        "Tracing")."""
         state, strat = self.state, self.strategy
-        avail = self.avail_model.draw(state.fleet.n_clients)
-        ctx = RoundContext(avail=avail,
-                           participants=self._draw_participants(),
-                           batch_fn=self._stack_batches,
-                           sample_indices=self._sample_indices,
-                           staleness=self._staleness.copy())
-        ws = strat.init_round(self, ctx)
-        stats = MET.RoundStats()
-        server_busy_s = 0.0
-        head_trained = False
-        for d, ids in strat.cohorts(self, ctx).items():
-            res = strat.cohort_step(self, ctx, ws, d, ids)
-            strat.fold_server(self, ws, d, ids, res)
-            server_busy_s += self._account_cohort(stats, ctx, d, ids, res)
-            # the global head learns when a cohort reaches the server — or
-            # trains the full model locally (serverless strategies)
-            if res.server_params == 0 or bool(ctx.avail[ids].any()):
-                head_trained = True
-        stats.round_time_s += server_busy_s
-        stats.energy_j += self.accountant.dm.server_power_w * server_busy_s
-        state.params, loss = strat.aggregate(self, ws)
+        rnd = state.round_idx + 1
+        compiles0 = BK.kernel_compiles()
+        self._counts = dict.fromkeys(_ROUND_COUNTS, 0)
+        with tracing.span("engine.round", round=rnd):
+            with tracing.span("engine.draw"):
+                avail = self.avail_model.draw(state.fleet.n_clients)
+                ctx = RoundContext(avail=avail,
+                                   participants=self._draw_participants(),
+                                   batch_fn=self._stack_batches,
+                                   sample_indices=self._sample_indices,
+                                   staleness=self._staleness.copy())
+            with tracing.span("strategy.init_round"):
+                ws = strat.init_round(self, ctx)
+            stats = MET.RoundStats()
+            server_busy_s = 0.0
+            head_trained = False
+            for d, ids in strat.cohorts(self, ctx).items():
+                with tracing.span("engine.cohort", round=rnd, depth=int(d),
+                                  clients=len(ids)):
+                    with tracing.span("strategy.cohort_step"):
+                        res = strat.cohort_step(self, ctx, ws, d, ids)
+                    with tracing.span("strategy.fold_server"):
+                        strat.fold_server(self, ws, d, ids, res)
+                    with tracing.span("engine.account"):
+                        server_busy_s += self._account_cohort(stats, ctx, d,
+                                                              ids, res)
+                # the global head learns when a cohort reaches the server
+                # — or trains the full model locally (serverless
+                # strategies)
+                if res.server_params == 0 or bool(ctx.avail[ids].any()):
+                    head_trained = True
+            stats.round_time_s += server_busy_s
+            stats.energy_j += self.accountant.dm.server_power_w \
+                * server_busy_s
+            with tracing.span("strategy.aggregate"):
+                state.params, loss = strat.aggregate(self, ws)
         trained = ctx.participants & state.fleet.feasible
         self._staleness = np.where(trained, 0, self._staleness + 1)
         if head_trained:
             self._server_updates += 1
         state.round_idx += 1
         self.accountant.log_round(stats)
-        rec = {"round": state.round_idx, "loss": loss,
+        rec = {"round": state.round_idx, "loss": loss, **self._counts,
+               "kernel_compiles": BK.kernel_compiles() - compiles0,
                **self.accountant.summary()}
         self.history.append(rec)
         return rec
+
+    def launch_kernel(self, kernel, bucket: int, *, width: float = 1.0,
+                      real: int = None):
+        """The :meth:`kernel_fn` callable for one cohort launch of
+        ``real`` clients (default: a full bucket) at width tier
+        ``width``: counted in the round record and run under the
+        ``cohort_kernel`` span, which covers the dispatch and nothing
+        else."""
+        run = self.kernel_fn(kernel, bucket)
+        real = bucket if real is None else int(real)
+
+        def call(*args):
+            counts = self._counts
+            counts["kernel_launches"] += 1
+            counts["kernel_slots"] += bucket
+            counts["kernel_real_slots"] += real
+            with tracing.span("cohort_kernel", width=float(width),
+                              bucket=bucket, real=real):
+                return run(*args)
+
+        return call
 
     def _draw_participants(self) -> np.ndarray:
         """sample_frac subset ∩ the participation arrival process (when one

@@ -114,7 +114,7 @@ class FedAvg(Strategy):
             lambda x: jnp.broadcast_to(x, (bucket,) + x.shape),
             state.params)
         dd = engine.device_data
-        kernel = engine.kernel_fn(step_kernel, bucket)
+        kernel = engine.launch_kernel(step_kernel, bucket, real=len(ids))
         pstack, losses = kernel(engine.cfg, engine.optimizer,
                                 engine.local_steps, pstack,
                                 dd.images, dd.labels, idx)

@@ -238,7 +238,8 @@ class SplitFedBase(Strategy):
         cstack, sstack = bcast(client_p), bcast(server_p)
         srv_state = base.broadcast_server_opt(srv_slice, server_p, bucket)
         dd = engine.device_data
-        kernel = engine.kernel_fn(cohort_kernel, bucket)
+        kernel = engine.launch_kernel(cohort_kernel, bucket, width=width,
+                                      real=len(ids))
         cstack, sstack, srv_state, loss = kernel(
             cfg, engine.optimizer, engine.local_steps, width,
             jnp.int32(d), cstack, sstack, dd.images, dd.labels, idx,

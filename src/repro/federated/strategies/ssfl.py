@@ -38,6 +38,7 @@ from repro.core import aggregation as AGG
 from repro.core import supernet as SN
 from repro.core import tpgf as T
 from repro.federated import bucketing as BK
+from repro.federated import tracing
 from repro.federated.strategies import base
 from repro.federated.strategies.base import (CohortResult, RoundContext,
                                              Strategy, register_strategy)
@@ -104,8 +105,9 @@ def cohort_kernel(cfg: ModelConfig, opt, steps: int, width: float, d,
 
     def step(carry, idx_t):
         cstack, lstack, srv_p, eph_state, s_state = carry
-        BK.guard_gather(idx_t, images.shape[0])   # sanitize-mode OOB check
-        batch = {"images": images[idx_t], "label": labels[idx_t]}
+        with jax.named_scope("cohort_kernel.gather"):
+            BK.guard_gather(idx_t, images.shape[0])   # sanitize-mode check
+            batch = {"images": images[idx_t], "label": labels[idx_t]}
 
         def one(cp, lp, b, av):
             # closes over the CARRY's server params: each local step sees
@@ -117,34 +119,36 @@ def cohort_kernel(cfg: ModelConfig, opt, steps: int, width: float, d,
 
         gc, gs, gl, l_c, l_s = jax.vmap(one, in_axes=(0, 0, 0, 0))(
             cstack, lstack, batch, avail)
-        # SuperSFL (Alg. 2 line 11): ONE shared main-server model, updated
-        # with the cohort's pooled gradient as the smashed batches stream
-        # in. Padded slots contribute zero to the pool and are excluded
-        # from the denominator; under shard_map the mean spans every shard.
-        gs_mean = BK.masked_slot_mean(gs, valid, axis_name)
-        eph_groups = {"client": cstack, "local": lstack}
-        eph_updates, eph_state = opt.update({"client": gc, "local": gl},
-                                            eph_state, eph_groups)
-        srv_updates, new_s_state = opt.update(gs_mean, s_state, srv_p)
-        new = apply_updates(eph_groups, eph_updates)
-        new_server = apply_updates(srv_p, srv_updates)
-        # runtime-depth row freeze: out-of-window stack rows must be a
-        # bit-exact no-op so the host's d=0 opt-state round trip and the
-        # aggregation's zero-pad contract both hold
-        new_client = SN.depth_freeze(cfg, new["client"], cstack, d,
-                                     keep="prefix", axis=1)
-        new_server = SN.depth_freeze(cfg, new_server, srv_p, d,
-                                     keep="suffix")
-        new_s_state = SN.depth_freeze(cfg, new_s_state, s_state, d,
-                                      keep="suffix")
-        # fault-tolerance invariant (tpgf "frozen server"): a cohort that
-        # never reached the server must be a bit-exact server no-op —
-        # carried moments would otherwise still step the params (momentum
-        # decay) and advance
-        freeze = lambda n_, o: jax.tree.map(
-            lambda a, b_: jnp.where(anyav, a, b_), n_, o)
-        new_server = freeze(new_server, srv_p)
-        s_state = freeze(new_s_state, s_state)
+        with jax.named_scope("cohort_kernel.update"):
+            # SuperSFL (Alg. 2 line 11): ONE shared main-server model,
+            # updated with the cohort's pooled gradient as the smashed
+            # batches stream in. Padded slots contribute zero to the pool
+            # and are excluded from the denominator; under shard_map the
+            # mean spans every shard.
+            gs_mean = BK.masked_slot_mean(gs, valid, axis_name)
+            eph_groups = {"client": cstack, "local": lstack}
+            eph_updates, eph_state = opt.update({"client": gc, "local": gl},
+                                                eph_state, eph_groups)
+            srv_updates, new_s_state = opt.update(gs_mean, s_state, srv_p)
+            new = apply_updates(eph_groups, eph_updates)
+            new_server = apply_updates(srv_p, srv_updates)
+            # runtime-depth row freeze: out-of-window stack rows must be a
+            # bit-exact no-op so the host's d=0 opt-state round trip and
+            # the aggregation's zero-pad contract both hold
+            new_client = SN.depth_freeze(cfg, new["client"], cstack, d,
+                                         keep="prefix", axis=1)
+            new_server = SN.depth_freeze(cfg, new_server, srv_p, d,
+                                         keep="suffix")
+            new_s_state = SN.depth_freeze(cfg, new_s_state, s_state, d,
+                                          keep="suffix")
+            # fault-tolerance invariant (tpgf "frozen server"): a cohort
+            # that never reached the server must be a bit-exact server
+            # no-op — carried moments would otherwise still step the
+            # params (momentum decay) and advance
+            freeze = lambda n_, o: jax.tree.map(
+                lambda a, b_: jnp.where(anyav, a, b_), n_, o)
+            new_server = freeze(new_server, srv_p)
+            s_state = freeze(new_s_state, s_state)
         return ((new_client, new["local"], new_server, eph_state,
                  s_state), (l_c, l_s))
 
@@ -187,15 +191,16 @@ class SuperSFL(Strategy):
     def cohort_step(self, engine, ctx, ws, d, ids) -> CohortResult:
         cfg, state = engine.cfg, engine.state
         sname = SN.split_stack_name(cfg)
-        # runtime depth: full-L views into the one compiled kernel per
-        # (width, bucket); the kernel masks/freezes rows by the traced d
-        client_p, server_p, _ = SN.split_params(cfg, state.params, None)
-        # the shared server branch's moments persist across rounds: hand
-        # the kernel the WHOLE state (d=0 slice = full copy) — it freezes
-        # moment rows < d in-kernel, so the d=0 merge below is bit-equal
-        # to the legacy depth-sliced round trip
-        srv_template, srv_full, srv_state = base.cohort_server_opt(
-            engine, cfg, sname, 0)
+        with tracing.span("strategy.split"):
+            # runtime depth: full-L views into the one compiled kernel per
+            # (width, bucket); the kernel masks/freezes rows by the traced d
+            client_p, server_p, _ = SN.split_params(cfg, state.params, None)
+            # the shared server branch's moments persist across rounds:
+            # hand the kernel the WHOLE state (d=0 slice = full copy) — it
+            # freezes moment rows < d in-kernel, so the d=0 merge below is
+            # bit-equal to the legacy depth-sliced round trip
+            srv_template, srv_full, srv_state = base.cohort_server_opt(
+                engine, cfg, sname, 0)
         losses = None
         csum = 0
         groups = self._width_groups(engine, ids)
@@ -203,8 +208,11 @@ class SuperSFL(Strategy):
         tiers, tier_states, live = [], [], []
         base_server, base_state = server_p, srv_state
         for w, gids in groups:
-            group_p = client_p if w >= 1.0 else \
-                SN.split_params(cfg, state.params, None, w)[0]
+            if w >= 1.0:
+                group_p = client_p
+            else:
+                with tracing.span("strategy.split"):
+                    group_p = SN.split_params(cfg, state.params, None, w)[0]
             # fused: every tier starts from the SAME server snapshot;
             # chained (legacy / comparator): from the previous tier's
             src = (base_server, base_state) if fused \
@@ -224,13 +232,15 @@ class SuperSFL(Strategy):
             # width 1.0 with its Eq. 6-style mass — summed inverse fused
             # losses of its live clients — and delta-mode fuse_tiers
             # keeps an all-frozen cohort a bit-exact server no-op
-            server_p = T.fuse_tiers(cfg, tiers, base=base_server,
-                                    use_pallas=cfg.use_pallas)
-            srv_state = self._fuse_server_state(
-                cfg, base_state, tier_states,
-                [t.weight for t in tiers], live, base_server)
-        state.opt_state["server"] = base.merge_server_opt(
-            srv_full, srv_state, srv_template, sname, 0)
+            with tracing.span("strategy.fuse_tiers"):
+                server_p = T.fuse_tiers(cfg, tiers, base=base_server,
+                                        use_pallas=cfg.use_pallas)
+                srv_state = self._fuse_server_state(
+                    cfg, base_state, tier_states,
+                    [t.weight for t in tiers], live, base_server)
+        with tracing.span("strategy.merge_server_opt"):
+            state.opt_state["server"] = base.merge_server_opt(
+                srv_full, srv_state, srv_template, sname, 0)
         cparams = csum // max(len(ids), 1)
         sparams = base.split_param_counts(cfg, state.params, d)[1]
         return CohortResult(cparams, sparams, payload=server_p,
@@ -281,37 +291,44 @@ class SuperSFL(Strategy):
         bs = engine.batch_size if batch_size is None else batch_size
         n = state.n_clients
         bucket = engine.bucket_for(len(ids))
-        pids = jnp.asarray(BK.pad_ids(np.asarray(ids), bucket, n))
-        valid = jnp.asarray(np.arange(bucket) < len(ids))
-        avail = jnp.asarray(BK.pad_rows(
-            np.asarray(ctx.avail[ids], bool), bucket, fill=False))
-        idx = jnp.asarray(BK.pad_slot_axis(
-            ctx.sample_indices(ids, engine.local_steps, bs), bucket, axis=1))
-        cstack = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (bucket,) + x.shape), client_p)
-        lstack = base.gather_rows(state.local_heads, pids)
-        dd = engine.device_data
-        kernel = engine.kernel_fn(cohort_kernel, bucket)
+        with tracing.span("strategy.stage", width=float(width),
+                          bucket=bucket):
+            pids = jnp.asarray(BK.pad_ids(np.asarray(ids), bucket, n))
+            valid = jnp.asarray(np.arange(bucket) < len(ids))
+            avail = jnp.asarray(BK.pad_rows(
+                np.asarray(ctx.avail[ids], bool), bucket, fill=False))
+            idx = jnp.asarray(BK.pad_slot_axis(
+                ctx.sample_indices(ids, engine.local_steps, bs), bucket,
+                axis=1))
+            cstack = jax.tree.map(
+                lambda x: jnp.broadcast_to(x, (bucket,) + x.shape), client_p)
+            lstack = base.gather_rows(state.local_heads, pids)
+            dd = engine.device_data
+            d_arr = jnp.int32(d)
+        kernel = engine.launch_kernel(cohort_kernel, bucket, width=width,
+                                      real=len(ids))
         cstack, lstack, server_p, srv_state, l_c, l_s = kernel(
             cfg, engine.optimizer, engine.local_steps, width,
-            jnp.int32(d), cstack, lstack, server_p, dd.images, dd.labels,
+            d_arr, cstack, lstack, server_p, dd.images, dd.labels,
             idx, avail, valid, srv_state)
-        # publish: heads + client trees scatter back (padded slots drop at
-        # the sentinel ids), per-slot losses stay on device
-        state.local_heads = base.scatter_rows(state.local_heads, pids,
-                                              lstack)
-        base.scatter_client_rows(cfg, ws, pids, cstack, d, width)
-        losses = jnp.where(
-            avail,
-            T.fused_loss(l_c, l_s, d, cfg.split_stack_len - d,
-                         cfg.tpgf_eps, cfg.tpgf_variant),
-            l_c)
-        base.record_cohort(ws, pids, losses)
-        # Eq. 6-style tier mass for cross-tier fusion: inverse fused loss,
-        # where-guarded over the slots that reached the server (padded and
-        # unreachable slots contribute exactly 0 — FL002 contract)
-        mass = jnp.sum(jnp.where(valid & avail,
-                                 1.0 / (losses + cfg.tpgf_eps), 0.0))
+        with tracing.span("strategy.publish"):
+            # heads + client trees scatter back (padded slots drop at the
+            # sentinel ids), per-slot losses stay on device
+            state.local_heads = base.scatter_rows(state.local_heads, pids,
+                                                  lstack)
+            base.scatter_client_rows(cfg, ws, pids, cstack, d, width)
+            losses = jnp.where(
+                avail,
+                T.fused_loss(l_c, l_s, d, cfg.split_stack_len - d,
+                             cfg.tpgf_eps, cfg.tpgf_variant),
+                l_c)
+            base.record_cohort(ws, pids, losses)
+            # Eq. 6-style tier mass for cross-tier fusion: inverse fused
+            # loss, where-guarded over the slots that reached the server
+            # (padded and unreachable slots contribute exactly 0 — FL002
+            # contract)
+            mass = jnp.sum(jnp.where(valid & avail,
+                                     1.0 / (losses + cfg.tpgf_eps), 0.0))
         return server_p, srv_state, losses, mass
 
     def fold_server(self, engine, ws, d, ids, res) -> None:
