@@ -34,6 +34,8 @@ views so TPGF can compute per-branch gradients without masking tricks.
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any, Dict, Tuple
 
 import jax
@@ -307,12 +309,49 @@ def depth_freeze(cfg: ModelConfig, new, old, d, *, keep: str,
     return new
 
 
+@functools.lru_cache(maxsize=None)
+def _split_sizes(cfg: ModelConfig, treedef, avals, d,
+                 width: float) -> Tuple[int, int, int]:
+    """(client count, server count, client + local bytes) of one split,
+    traced from shapes alone: ``split_params`` under ``jax.eval_shape``."""
+    tree = jax.tree_util.tree_unflatten(
+        treedef, [jax.ShapeDtypeStruct(s, dt) for s, dt in avals])
+    client, server, local = jax.eval_shape(
+        lambda p: split_params(cfg, p, d, width), tree)
+    count = lambda t: sum(math.prod(x.shape) for x in jax.tree.leaves(t))
+    nbytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                 for x in jax.tree.leaves((client, local)))
+    return count(client), count(server), nbytes
+
+
+def split_sizes(cfg: ModelConfig, params: Params, d,
+                width: float = 1.0) -> Tuple[int, int, int]:
+    """(client parameter count, server parameter count, client + local
+    bytes) of the depth-``d`` width-``w`` split.
+
+    Computed from the parameters' tree structure, shapes and dtypes only
+    — ``params`` may be device arrays or ``jax.ShapeDtypeStruct`` leaves
+    — and cached by (cfg, that signature, d, width), so a fleet's
+    accounting traces each split once and later rounds, whose parameters
+    are new arrays of the same shapes, read the cache. No device program
+    runs."""
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    avals = tuple((tuple(x.shape), jnp.dtype(x.dtype)) for x in leaves)
+    return _split_sizes(cfg, treedef, avals,
+                        None if d is None else int(d), float(width))
+
+
+def size_cache_misses() -> int:
+    """Splits sized so far (cache misses of :func:`split_sizes`); a delta
+    around a round counts the accounting work that round did."""
+    return _split_sizes.cache_info().misses
+
+
 def client_param_bytes(cfg: ModelConfig, params: Params, d: int,
                        width: float = 1.0) -> int:
-    """Size of a (depth, width) subnetwork — the per-round download cost."""
-    client, _, local = split_params(cfg, params, d, width)
-    leaves = jax.tree.leaves(client) + jax.tree.leaves(local)
-    return sum(int(x.size) * x.dtype.itemsize for x in leaves)
+    """Size of a (depth, width) subnetwork — the per-round download cost
+    (client view plus local head; :func:`split_sizes`)."""
+    return split_sizes(cfg, params, d, width)[2]
 
 
 def smashed_bytes(z) -> int:

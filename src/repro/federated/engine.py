@@ -18,8 +18,9 @@ round's training outputs accumulate in full-fleet stacked device buffers
 (``strategies.base.fleet_workspace``) that aggregation consumes directly
 with a validity mask. Host floats materialize once per round — the
 trained-mask/loss sync in ``Strategy._finish_aggregation`` — plus the pure
-cost-model arithmetic in ``_account_cohort``, which never touches device
-data.
+cost-model arithmetic in ``_account_cohort``, which sizes subnetworks from
+parameter shapes (``supernet.split_sizes``, cached by depth and width) and
+never touches device data.
 
 Multi-device fleet execution
 ----------------------------
@@ -79,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import supernet as SN
 from repro.core.fault import ArrivalProcess, AvailabilityModel
 from repro.federated import bucketing as BK
 from repro.federated import metrics as MET
@@ -277,13 +279,15 @@ class Engine:
         """One federated round. Returns (and appends to ``history``) the
         round record: ``round``, ``loss``, the kernel counters of the
         round (``kernel_launches``, ``kernel_slots``,
-        ``kernel_real_slots``, ``kernel_compiles``; host integers, no
-        device sync) and the accountant's running summary. Every phase
-        runs under a host span (``tracing.span``; docs/architecture.md,
-        "Tracing")."""
+        ``kernel_real_slots``, ``kernel_compiles``, and
+        ``size_cache_misses``, the splits the accounting had to size; host
+        integers, no device sync) and the accountant's running summary.
+        Every phase runs under a host span (``tracing.span``;
+        docs/architecture.md, "Tracing")."""
         state, strat = self.state, self.strategy
         rnd = state.round_idx + 1
         compiles0 = BK.kernel_compiles()
+        misses0 = SN.size_cache_misses()
         self._counts = dict.fromkeys(_ROUND_COUNTS, 0)
         with tracing.span("engine.round", round=rnd):
             with tracing.span("engine.draw"):
@@ -326,6 +330,7 @@ class Engine:
         self.accountant.log_round(stats)
         rec = {"round": state.round_idx, "loss": loss, **self._counts,
                "kernel_compiles": BK.kernel_compiles() - compiles0,
+               "size_cache_misses": SN.size_cache_misses() - misses0,
                **self.accountant.summary()}
         self.history.append(rec)
         return rec
@@ -390,8 +395,10 @@ class Engine:
                         d: int, ids, res) -> float:
         """Method-independent cost model over one cohort; returns the
         server busy-time contribution (0 for serverless strategies). Pure
-        host arithmetic over profile scalars — device arrays are never
-        synced here."""
+        host arithmetic over profile scalars and parameter shapes:
+        ``comm_cost`` sizes subnetworks through ``supernet.split_sizes``,
+        cached by (cfg, shapes, depth, width), so no device program runs
+        here and later rounds size nothing new (``size_cache_misses``)."""
         dm = self.accountant.dm
         # co-tuning strategies report their cohort's effective batch tokens
         n_tok = res.tokens_per_batch or self.tokens_per_batch()

@@ -247,14 +247,10 @@ def scatter_client_rows(cfg, ws: Dict[str, Any], ids, cstack, d: int,
 
 def split_param_counts(cfg, params, d: int, width: float = 1.0):
     """(client, server) parameter counts of the depth-``d`` width-``w``
-    split, via ``jax.eval_shape`` — no device work. The runtime-depth
-    cohort path hands full-``L`` views to the kernels, so per-cohort
-    accounting can no longer just count the view's leaves."""
-    c, s, _ = jax.eval_shape(lambda p: SN.split_params(cfg, p, d, width),
-                             params)
-    count = lambda t: sum(int(np.prod(x.shape))
-                          for x in jax.tree.leaves(t))
-    return count(c), count(s)
+    split, from shapes (``supernet.split_sizes``) — no device work. The
+    runtime-depth cohort path hands full-``L`` views to the kernels, so
+    per-cohort accounting can no longer just count the view's leaves."""
+    return SN.split_sizes(cfg, params, d, width)[:2]
 
 
 def record_cohort(ws: Dict[str, Any], ids, losses):

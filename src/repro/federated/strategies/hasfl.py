@@ -184,13 +184,9 @@ class HASFL(SuperSFL):
             widths = getattr(engine.state.fleet, "widths", None)
             if widths is not None and bool((np.asarray(widths) < 1.0).any()):
                 # width-tiered download: each client ships only its slice
-                by_tier: Dict[float, int] = {}
                 pbytes = np.array(
-                    [by_tier.setdefault(
-                        float(widths[i]),
-                        SN.client_param_bytes(engine.cfg,
-                                              engine.state.params, d,
-                                              float(widths[i])))
+                    [SN.client_param_bytes(engine.cfg, engine.state.params,
+                                           d, float(widths[i]))
                      for i in np.asarray(ids)], np.int64)
             return (2 * pbytes + engine.local_steps * per_step,
                     np.full(len(bs), msgs, np.int64))

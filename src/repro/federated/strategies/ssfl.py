@@ -364,12 +364,9 @@ class SuperSFL(Strategy):
         hetero = widths is not None and bool(
             (np.asarray(widths) < 1.0).any())
         if ids is not None and hetero:
-            by_tier: Dict[float, int] = {}
             pbytes = np.array(
-                [by_tier.setdefault(
-                    float(widths[i]),
-                    SN.client_param_bytes(engine.cfg, engine.state.params,
-                                          d, float(widths[i])))
+                [SN.client_param_bytes(engine.cfg, engine.state.params, d,
+                                       float(widths[i]))
                  for i in np.asarray(ids)], np.int64)
             return (2 * pbytes + engine.local_steps * per_step,
                     np.full(len(pbytes), msgs, np.int64))

@@ -245,3 +245,43 @@ class TestCommCostSignatureProbe:
         assert scalar_bytes == 2 * pbytes + eng.local_steps * 2 * int(
             (28 / 3) * per_tok)
         assert msgs == 2 + 2 * eng.local_steps
+
+
+class TestShapeAccounting:
+    """The per-cohort accounting sizes subnetworks from shapes cached by
+    (depth, width): a heterogeneous-width ``ssfl`` fleet's round stats
+    equal those of the same run priced by slicing the live parameters,
+    and from round 2 on the accounting sizes nothing new."""
+
+    @staticmethod
+    def _eager_sizes(cfg, params, d, width=1.0):
+        from repro.core import supernet as SN
+        client, server, local = SN.split_params(cfg, params, d, width)
+        count = lambda t: sum(int(x.size) for x in jax.tree.leaves(t))
+        nbytes = sum(int(x.size) * x.dtype.itemsize
+                     for x in jax.tree.leaves(client)
+                     + jax.tree.leaves(local))
+        return count(client), count(server), nbytes
+
+    def _run(self, rounds):
+        eng = _engine("ssfl", n_clients=5, width_tiers=(0.5, 1.0),
+                      availability=0.7)
+        assert len(set(eng.state.fleet.widths.tolist())) > 1
+        recs = [eng.run_round() for _ in range(rounds)]
+        return eng, recs
+
+    def test_round_stats_match_eager_sizes(self, monkeypatch):
+        from repro.core import supernet as SN
+        eng, recs = self._run(3)
+        assert [r["size_cache_misses"] for r in recs[1:]] == [0, 0]
+        with monkeypatch.context() as m:
+            m.setattr(SN, "split_sizes", self._eager_sizes)
+            ref, ref_recs = self._run(3)
+        for got, want in zip(eng.accountant.rounds, ref.accountant.rounds):
+            assert got.comm_bytes == want.comm_bytes > 0
+            assert got.client_flops == want.client_flops > 0
+            assert got.server_flops == want.server_flops
+            assert got.n_messages == want.n_messages
+        assert [r["loss"] for r in recs] == [r["loss"] for r in ref_recs]
+        assert [r["comm_mb"] for r in recs] == \
+            [r["comm_mb"] for r in ref_recs]

@@ -15,6 +15,7 @@ Pins the four width views of ``repro.core.supernet`` and their algebra:
 Property tests need hypothesis (dev extras); they skip clean without it,
 the deterministic classes below always run.
 """
+import functools
 import os
 import tempfile
 
@@ -221,6 +222,56 @@ class TestFullWidthIdentity:
                  for w in (0.25, 0.5, 0.75, 1.0)]
         assert sizes == sorted(sizes)
         assert sizes[0] < sizes[-1]
+
+
+# ----------------------------------------------- shape-cached split sizes
+
+_SIZE_CFGS = {"vit16_cifar": CFG,
+              "whisper_small": base.get_reduced("whisper_small")}
+
+
+@functools.lru_cache(maxsize=None)
+def _family_params(family: str, seed: int):
+    return M.init_params(_SIZE_CFGS[family], jax.random.PRNGKey(seed))
+
+
+def _eager_sizes(cfg, params, d, width):
+    """The split's sizes summed over eagerly sliced leaves: (client count,
+    server count, client + local bytes)."""
+    client, server, local = SN.split_params(cfg, params, d, width)
+    count = lambda t: sum(int(x.size) for x in jax.tree.leaves(t))
+    nbytes = sum(int(x.size) * x.dtype.itemsize
+                 for x in jax.tree.leaves(client) + jax.tree.leaves(local))
+    return count(client), count(server), nbytes
+
+
+class TestSplitSizes:
+    """``client_param_bytes`` and ``split_param_counts`` come from shapes
+    through a cache keyed by (cfg, shape signature, d, width): the same
+    integers as slicing the live parameters, from abstract parameters
+    too, and no new miss for new arrays of the same shapes."""
+
+    @pytest.mark.parametrize("width", (0.25, 0.5, 1.0))
+    @pytest.mark.parametrize("d", (1, 2))
+    @pytest.mark.parametrize("family", sorted(_SIZE_CFGS))
+    def test_cached_equals_eager(self, family, d, width):
+        from repro.federated.strategies import base as SB
+        cfg, params = _SIZE_CFGS[family], _family_params(family, 0)
+        ccount, scount, nbytes = _eager_sizes(cfg, params, d, width)
+        abstract = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
+        misses0 = SN.size_cache_misses()
+        assert SN.client_param_bytes(cfg, abstract, d, width) == nbytes
+        assert SN.size_cache_misses() - misses0 <= 1
+        misses1 = SN.size_cache_misses()
+        assert SN.client_param_bytes(cfg, params, d, width) == nbytes
+        assert SB.split_param_counts(cfg, abstract, d, width) == (
+            ccount, scount)
+        fresh = _family_params(family, 1)
+        assert SN.client_param_bytes(cfg, fresh, d, width) == nbytes
+        assert SB.split_param_counts(cfg, fresh, d, width) == (
+            ccount, scount)
+        assert SN.size_cache_misses() == misses1
 
 
 # ------------------------------------------- engine-level width behavior
