@@ -1,9 +1,14 @@
-"""The work a SuperSFL round requires, from shapes alone.
+"""The ``vit`` family: weights, data and required work of a ViT classifier.
 
-Counts what Alg. 2/3 needs, not what an implementation executes: matmul
-FLOPs (2 per multiply-add) of real clients only — no padded bucket slots,
-no masked inactive rows of a runtime-depth scan, no recomputation.
-Normalisations, softmax and activations are not counted.
+What ``spec.Cell.family`` asks of a family (``bench/spec.py``), for the
+system's ViT: a patch projection plus learned positions, pre-norm encoder
+layers, mean pooling, a linear head, on CIFAR-shaped images.
+
+The work a SuperSFL round requires, from shapes alone. Counts what Alg. 2/3
+needs, not what an implementation executes: matmul FLOPs (2 per
+multiply-add) of real clients only — no padded bucket slots, no masked
+inactive rows of a runtime-depth scan, no recomputation. Normalisations,
+softmax and activations are not counted.
 
 Per real client, per local step, per sample of ``T`` tokens:
 
@@ -27,6 +32,59 @@ the group's server branch read and written once, and the batch read once;
 float32. SGD keeps no optimizer state.
 """
 from __future__ import annotations
+
+import math
+
+# ------------------------------------------------------------------ weights
+
+
+def weight_shapes(model: dict, n_clients: int):
+    """(params, local_heads) as trees of (shape, init std or 'zeros'/'ones')."""
+    D, L, C = model["d_model"], model["n_layers"], model["n_classes"]
+    H, hd, F = model["n_heads"], model["head_dim"], model["d_ff"]
+    p = model["patch_size"]
+    T = (model["image_size"] // p) ** 2
+    out_std = 0.02 / math.sqrt(2 * L)
+    layers = {
+        "attn_norm_scale": ((L, D), "ones"),
+        "attn_norm_bias": ((L, D), "zeros"),
+        "attn": {"wq": ((L, D, H * hd), 0.02), "wk": ((L, D, H * hd), 0.02),
+                 "wv": ((L, D, H * hd), 0.02),
+                 "wo": ((L, H * hd, D), out_std)},
+        "mlp_norm_scale": ((L, D), "ones"),
+        "mlp_norm_bias": ((L, D), "zeros"),
+        "mlp": {"w_up": ((L, D, F), 0.02), "b_up": ((L, F), "zeros"),
+                "w_down": ((L, F, D), out_std), "b_down": ((L, D), "zeros")},
+    }
+    params = {"patch_embed": ((p * p * 3, D), 0.02),
+              "patch_bias": ((D,), "zeros"),
+              "pos_embed": ((T, D), 0.02),
+              "layers": layers,
+              "head": ((D, C), 0.02), "head_bias": ((C,), "zeros"),
+              "local_head": ((D, C), 0.02),
+              "local_head_bias": ((C,), "zeros")}
+    heads = {"local_head": ((n_clients, D, C), 0.02),
+             "local_head_bias": ((n_clients, C), "zeros")}
+    return params, heads
+
+
+# --------------------------------------------------------------------- data
+
+
+def make_samples(model: dict, traffic: dict, rng):
+    """CIFAR-shaped synthetic images, [S, H, W, 3] float64, and their
+    labels: a fixed prototype per class plus Gaussian noise — a copy of the
+    program's own generator (``repro.data.synthetic``), kept here so a
+    program change cannot move it."""
+    C, S = model["n_classes"], model["image_size"]
+    protos = rng.normal(0.0, 1.0, (C, S, S, 3))
+    labels = rng.integers(0, C, traffic["samples"])
+    images = protos[labels] + rng.normal(0.0, traffic["noise"],
+                                         (traffic["samples"], S, S, 3))
+    return images, labels
+
+
+# -------------------------------------------------------------------- work
 
 
 def kept(model: dict, width: float):

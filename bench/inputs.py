@@ -4,11 +4,12 @@ The program under test and the plain reference both take their inputs from
 here, so the reference never reads anything the program made:
 
 * weights: one jitted call on the device, in the layout the engine holds
-  (``params`` tree + stacked ``[N, ...]`` local heads), float32;
-* the federated dataset: CIFAR-shaped synthetic images (a fixed prototype
-  per class plus Gaussian noise) split over the clients by Dirichlet(alpha),
-  made in bulk with numpy — a copy of the program's own generator
-  (``repro.data.synthetic``), kept here so a program change cannot move it;
+  (``params`` tree + stacked ``[N, ...]`` local heads), float32, from the
+  shapes the configuration's family states (``bench/families/``);
+* the federated dataset: the family's synthetic samples, made in bulk with
+  numpy, split over the clients by Dirichlet(alpha) — a copy of the
+  program's own split (``repro.data.synthetic``), kept here so a program
+  change cannot move it;
 * the fleet: each client's depth and width tier, stated as data in the
   traffic mix;
 * the server-availability and batch-index streams, as seeds; the draw
@@ -20,7 +21,6 @@ stream is derived with ``numpy.random.SeedSequence([seed, stream])``.
 from __future__ import annotations
 
 import dataclasses
-import math
 
 import numpy as np
 
@@ -51,47 +51,17 @@ def batch_seed(seed: int) -> int:
 
 # ------------------------------------------------------------------ weights
 
-def weight_shapes(model: dict, n_clients: int):
-    """(params, local_heads) as trees of (shape, init std or 'zeros'/'ones')."""
-    D, L, C = model["d_model"], model["n_layers"], model["n_classes"]
-    H, hd, F = model["n_heads"], model["head_dim"], model["d_ff"]
-    p = model["patch_size"]
-    T = (model["image_size"] // p) ** 2
-    out_std = 0.02 / math.sqrt(2 * L)
-    layers = {
-        "attn_norm_scale": ((L, D), "ones"),
-        "attn_norm_bias": ((L, D), "zeros"),
-        "attn": {"wq": ((L, D, H * hd), 0.02), "wk": ((L, D, H * hd), 0.02),
-                 "wv": ((L, D, H * hd), 0.02),
-                 "wo": ((L, H * hd, D), out_std)},
-        "mlp_norm_scale": ((L, D), "ones"),
-        "mlp_norm_bias": ((L, D), "zeros"),
-        "mlp": {"w_up": ((L, D, F), 0.02), "b_up": ((L, F), "zeros"),
-                "w_down": ((L, F, D), out_std), "b_down": ((L, D), "zeros")},
-    }
-    params = {"patch_embed": ((p * p * 3, D), 0.02),
-              "patch_bias": ((D,), "zeros"),
-              "pos_embed": ((T, D), 0.02),
-              "layers": layers,
-              "head": ((D, C), 0.02), "head_bias": ((C,), "zeros"),
-              "local_head": ((D, C), 0.02),
-              "local_head_bias": ((C,), "zeros")}
-    heads = {"local_head": ((n_clients, D, C), 0.02),
-             "local_head_bias": ((n_clients, C), "zeros")}
-    return params, heads
-
-
 def _is_spec(x):
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 
-def make_weights(model: dict, n_clients: int, seed: int, dtype="float32"):
-    """Initial (params, local_heads) on the default device, in ONE jitted
-    call: normal(0, std) matrices, zero biases, unit norm scales."""
+def make_weights(specs, seed: int, dtype="float32"):
+    """The weights a family's ``weight_shapes`` describes, on the default
+    device, in ONE jitted call: normal(0, std) matrices, zero biases, unit
+    norm scales."""
     import jax
     import jax.numpy as jnp
 
-    specs = weight_shapes(model, n_clients)
     flat, treedef = jax.tree_util.tree_flatten(specs, is_leaf=_is_spec)
     dt = jnp.dtype(dtype)
 
@@ -123,7 +93,7 @@ class Shard:
 
 @dataclasses.dataclass
 class Dataset:
-    images: np.ndarray        # [S, H, W, 3] float32, client shards in order
+    images: np.ndarray        # [S, ...] float32 inputs, client shards in order
     labels: np.ndarray        # [S] int32
     offsets: np.ndarray       # [N] first flat index of each client's shard
     sizes: np.ndarray         # [N] shard sizes
@@ -138,14 +108,13 @@ class Dataset:
         return {"clients": self.shards(), "test": None}
 
 
-def make_dataset(model: dict, traffic: dict, seed: int) -> Dataset:
+def make_dataset(family, model: dict, traffic: dict, seed: int) -> Dataset:
+    """The family's synthetic samples (``family.make_samples``, from the
+    data stream), split over the clients by Dirichlet(alpha) and laid out
+    shard after shard."""
     n_clients = traffic["n_clients"]
-    C, S = model["n_classes"], model["image_size"]
     rng = np.random.default_rng(stream_seed(seed, _DATA))
-    protos = rng.normal(0.0, 1.0, (C, S, S, 3))
-    labels = rng.integers(0, C, traffic["samples"])
-    images = protos[labels] + rng.normal(0.0, traffic["noise"],
-                                         (traffic["samples"], S, S, 3))
+    images, labels = family.make_samples(model, traffic, rng)
     shards = dirichlet_partition(labels, n_clients, traffic["dirichlet_alpha"],
                                  stream_seed(seed, _PARTITION))
     order = np.concatenate(shards)

@@ -1,8 +1,8 @@
 """Share of its roofline the cohort kernel reaches, in %: the least time
 the chip could take for the work the window's rounds required — the larger
 of required FLOPs over the bf16 peak and required bytes over HBM bandwidth
-(``bench/flops.py``; FLOPs set it in the benchmark's cell) — over the
-device time of the kernel's executions."""
+(the family's ``round_work``, ``bench/families/``; FLOPs set it in the
+benchmark's cells) — over the device time of the kernel's executions."""
 
 
 def read(ctx):

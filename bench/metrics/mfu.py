@@ -1,6 +1,7 @@
 """Model FLOP/s utilisation of the whole round: the FLOPs the traced
-window's rounds required (``bench/flops.py``) over the window's seconds,
-the chips and their bf16 peak, in %."""
+window's rounds required (the family's ``round_work``,
+``bench/families/``) over the window's seconds, the chips and their bf16
+peak, in %."""
 
 
 def read(ctx):

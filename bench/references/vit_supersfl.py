@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.flops import kept
+from bench.families.vit import kept
 from bench.inputs import batch_indices
 
 PLAN = {("attn", "wq"): -1, ("attn", "wk"): -1, ("attn", "wv"): -1,

@@ -13,8 +13,9 @@ A run, in order:
    the fleet it allocates from the traffic's ``fleet_seed`` (each client's
    depth and width) is the one the traffic states (exit 4, no result
    line, where it is not), and hands it the
-   benchmark's inputs for ``--seed`` (``bench/inputs.py``): weights,
-   dataset, server availability and batch streams;
+   benchmark's inputs for ``--seed`` (``bench/inputs.py``, with the
+   configuration's family, ``bench/families/``): weights, dataset, server
+   availability and batch streams;
 3. runs three rounds through ``Engine.run_round`` — the window's own call —
    recording each round's loss and the change of every parameter leaf
    after rounds 1 and 3, then further whole rounds until one compiles
@@ -51,7 +52,7 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from bench import compare, flops, inputs, spec  # noqa: E402
+from bench import compare, inputs, spec  # noqa: E402
 
 TRACE_DIR = os.path.join(ROOT, "bench", ".trace")
 MAX_WARM_ROUNDS = 4       # extra warm-up rounds allowed after the first 3
@@ -201,10 +202,10 @@ def build(cell: spec.Cell, seed: int, aggregation_precision="stated"):
     from repro.core.fault import AvailabilityModel
     from repro.federated import Engine
 
-    m, t = cell.model, cell.traffic
+    m, t, family = cell.model, cell.traffic, cell.family()
     if cell.precision != "default":
         jax.config.update("jax_default_matmul_precision", cell.precision)
-    data = inputs.make_dataset(m, t, seed)
+    data = inputs.make_dataset(family, m, t, seed)
     fleet = inputs.make_fleet(t)
     engine = (Engine.builder(ModelConfig(**m))
               .clients(t["n_clients"], availability=AvailabilityModel(
@@ -224,7 +225,8 @@ def build(cell: spec.Cell, seed: int, aggregation_precision="stated"):
             f"{np.asarray(f.widths).tolist()} feasible "
             f"{f.feasible.tolist()}; the traffic states depths "
             f"{fleet.depths.tolist()} widths {fleet.widths.tolist()}")
-    params, heads = inputs.make_weights(m, t["n_clients"], seed)
+    params, heads = inputs.make_weights(
+        family.weight_shapes(m, t["n_clients"]), seed)
     _same_layout(engine.state.params, params, "params")
     _same_layout(engine.state.local_heads, heads, "local_heads")
     engine.state.params, engine.state.local_heads = params, heads
@@ -309,7 +311,8 @@ def reference_readings(cell: spec.Cell, seed: int, data, fleet, *,
     import numpy as np
     ref = cell.reference()
     m, t = cell.model, cell.traffic
-    params, heads = inputs.make_weights(m, t["n_clients"], seed)
+    params, heads = inputs.make_weights(
+        cell.family().weight_shapes(m, t["n_clients"]), seed)
     avail = inputs.availability(seed, t["n_clients"], CHECK_ROUNDS,
                                 t["availability"])
     rng = np.random.default_rng(inputs.batch_seed(seed))
@@ -434,7 +437,8 @@ def main(argv=None) -> int:
     slots, real, sample_rows = rec.slots(launch0)
     samples = sample_rows * t["batch_size"]
     mem_peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
-    work = [flops.round_work(m, t, groups, a) for a in rec.avail[avail0:]]
+    work = [cell.family().round_work(m, t, groups, a)
+            for a in rec.avail[avail0:]]
     failed = sum(1 for x in losses if not math.isfinite(x))
 
     drawn = rec.avail[:CHECK_ROUNDS]

@@ -11,6 +11,11 @@ per-layer metric is a file of its own, found by name:
 * ``bench/metrics/<metric>.py``: the reader of one per-layer metric, a
   ``read(ctx)`` that returns a number or None;
 * ``bench/references/<name>.py``: a configuration's plain reference;
+* ``bench/families/<model.family>.py``: what the harness needs to know of
+  a configuration's model family: ``weight_shapes(model, n_clients)``,
+  ``make_samples(model, traffic, rng)``, ``kept(model, width)`` and
+  ``round_work(model, traffic, groups, avail)`` (``bench/families/vit.py``
+  documents each);
 * ``bench/peaks.json``: the chips' peaks, keyed by ``device_kind``.
 """
 from __future__ import annotations
@@ -49,6 +54,7 @@ class Cell:
     cell: dict            # the cell file (lr, limits)
     end_to_end: list      # BENCHMARK.json metric entries of this cell
     per_layer: list
+    root: str = ROOT      # the checkout the files were found in
 
     @property
     def model(self) -> dict:
@@ -76,12 +82,18 @@ class Cell:
                     self.precision, ("bfloat16", "default"))
 
     def reference(self):
-        return _module(os.path.join(BENCH, "references",
+        return _module(os.path.join(self.root, "bench", "references",
                                     self.config["reference"] + ".py"),
                        "bench_reference_" + self.config["reference"])
 
+    def family(self):
+        name = self.model["family"]
+        return _module(os.path.join(self.root, "bench", "families",
+                                    name + ".py"), "bench_family_" + name)
+
     def reader(self, metric: str):
-        return _module(os.path.join(BENCH, "metrics", metric + ".py"),
+        return _module(os.path.join(self.root, "bench", "metrics",
+                                    metric + ".py"),
                        "bench_metric_" + metric.replace(".", "_"))
 
 
@@ -104,7 +116,8 @@ def load(name: str, root: str = ROOT) -> Cell:
         cell=_load_json(os.path.join(root, "bench", "workloads",
                                      name + ".json")),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
-        per_layer=[m for m in bench["per_layer"] if _applies(m, name)])
+        per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
+        root=root)
 
 
 def peaks(device_kind: str) -> dict:
